@@ -25,7 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.dist.compat import shard_map
 
 
 def agree_fault(observations: dict[int, set[int]], live: list[int]) -> set[int]:
@@ -75,7 +74,7 @@ def agree_bitmap_inprogram(mesh: Mesh, bitmaps: jax.Array) -> np.ndarray:
     shard_axes = axes if len(axes) > 1 else axes[0]
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=P(shard_axes, None),
         out_specs=P(None),
     )

@@ -45,7 +45,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.hierarchy import LegionTopology
-from repro.dist.compat import shard_map
 
 # Operation classes (paper §V)
 ONE_TO_ONE = "one_to_one"
@@ -398,7 +397,7 @@ def make_hierarchical_allreduce(mesh: Mesh, spec: P):
     names = mesh.axis_names
     has_pod = "pod" in names
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=spec, out_specs=spec)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=spec, out_specs=spec)
     def _allreduce(x):
         if has_pod:
             return hierarchical_psum(x, legion_axis="pod", member_axis="data")

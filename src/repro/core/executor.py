@@ -411,13 +411,15 @@ class VirtualCluster:
                                setter: Callable[[Any], None] | None = None
                                ) -> None:
         """Register a live-state pytree (via getter/setter) for post-repair
-        redistribution on the data plane. On the jax plane every repair that
+        redistribution on the data plane. On the jax plane the tree is
+        placed on the current mesh at once, and every repair that
         changes membership triggers a mesh rebuild + one measured device_put
         pass over each registered tree (charged to the clock from wall
         time); on the sim plane this is bookkeeping only. Consumers call
         this — never the data plane directly — so backend selection stays
         behind LegioPolicy/Session."""
-        self.dataplane.register_state(name, getter, setter)
+        self.dataplane.register_state(name, getter, setter,
+                                      view=self.topo.view())
 
     def _reshard_after_repair(self) -> None:
         """Redistribute registered state onto the survivors' mesh — the

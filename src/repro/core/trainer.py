@@ -123,7 +123,12 @@ class ResilientTrainer:
         self.history: list[TrainerReport] = []
         # live state rides the data plane's mesh: after every shrink or
         # regrow the surviving devices re-place params/opt in one measured
-        # device_put pass (a no-op on the sim plane)
+        # device_put pass (a no-op on the sim plane). Every argument of
+        # train_step that carries state is registered, the step counter
+        # too, so all of them always sit on the same device set.
+        self.session.register_sharded_state(
+            "trainer.opt.step", lambda: self.opt.step,
+            lambda s: setattr(self, "opt", self.opt._replace(step=s)))
         self.session.register_sharded_state(
             "trainer.params", lambda: self.params,
             lambda p: setattr(self, "params", p))

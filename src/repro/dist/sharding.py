@@ -136,7 +136,9 @@ def param_specs(cfg, params: PyTree, mesh) -> PyTree:
     del cfg  # rules are name-based; cfg kept for signature stability
 
     def leaf_spec(path, leaf):
-        spec = P(*_param_rule(_key_name(path[-1]), len(leaf.shape)))
+        # a bare array (empty path) follows no rule: replicated
+        rule = _param_rule(_key_name(path[-1]), len(leaf.shape)) if path else ()
+        spec = P(*rule)
         name = ".".join(_key_name(e) for e in path)
         return sanitize_spec(spec, leaf.shape, mesh, param=name)
 

@@ -1,9 +1,10 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode —
-the kernel body runs in Python op-by-op, which validates indexing, masking
-and the online-softmax/recurrence algebra exactly as the TPU grid would
-sequence them. On TPU backends the same call sites lower to Mosaic.
+On the CPU backend (the test path) the kernels execute in
+``interpret=True`` mode — the kernel body runs op-by-op, which validates
+indexing, masking and the online-softmax/recurrence algebra exactly as the
+TPU grid would sequence them. On TPU the same call sites lower to Mosaic.
+Any other backend is an error: a kernel never silently interprets there.
 """
 from __future__ import annotations
 
@@ -16,8 +17,14 @@ from repro.kernels.quantize import quantize_int8_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def interpret_mode() -> bool:
+    """True on the CPU backend, False on TPU; raises on any other."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run on TPU (or interpreted on CPU), not on "
+            f"backend {backend!r}")
+    return backend == "cpu"
 
 
 @functools.partial(
@@ -31,7 +38,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     return flash_attention_pallas(
         q, k, v, causal=causal, window=window, logit_softcap=logit_softcap,
         q_offset=q_offset, block_q=min(block_q, q.shape[1]),
-        block_k=min(block_k, k.shape[1]), interpret=_interpret())
+        block_k=min(block_k, k.shape[1]), interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, static_argnames=("chunk",))
@@ -39,10 +46,10 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 256, initial_state=None):
     """Chunked SSD scan; returns (y (B,S,H,P), final_state (B,H,P,N) f32)."""
     return ssd_scan_pallas(x, dt, A, Bm, Cm, chunk=chunk,
                            initial_state=initial_state,
-                           interpret=_interpret())
+                           interpret=interpret_mode())
 
 
 @jax.jit
 def quantize_int8(g):
     """Int8 absmax quantization (the compression hop); returns Int8Grad."""
-    return quantize_int8_pallas(g, interpret=_interpret())
+    return quantize_int8_pallas(g, interpret=interpret_mode())

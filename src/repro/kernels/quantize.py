@@ -5,10 +5,13 @@ error-fed partial to int8 before it rides the slow links
 (optim/compression.py). On device that is two passes over the flattened
 tensor, both expressed as a Pallas grid over ``(block_rows, 128)`` tiles:
 
-  1. ``absmax`` — a running max of |x| accumulated across grid steps into a
-     (1, 1) output block. TPU cores execute the grid sequentially, so the
-     same output block is a legal cross-step accumulator (the SSD scan's
-     VMEM-state idiom applied to a reduction).
+  1. ``absmax`` — a running per-lane max of |x| accumulated across grid
+     steps into a (1, 128) output block, reduced to a scalar after the
+     kernel. TPU cores execute the grid sequentially, so the same output
+     block is a legal cross-step accumulator (the SSD scan's VMEM-state
+     idiom applied to a reduction); a lane-wide block, because Mosaic
+     cannot store a scalar to VMEM. Max is exact, so the layout cannot
+     change the result.
   2. ``quantize`` — elementwise ``clip(round(x / scale), -127, 127)`` into
      an int8 tile, with the (1, 1) scale block broadcast to every step.
 
@@ -45,9 +48,10 @@ def _absmax_kernel(x_ref, out_ref):
 
     @pl.when(i == 0)
     def _init():
-        out_ref[0, 0] = 0.0
+        out_ref[...] = jnp.zeros_like(out_ref)
 
-    out_ref[0, 0] = jnp.maximum(out_ref[0, 0], jnp.max(jnp.abs(x_ref[...])))
+    out_ref[...] = jnp.maximum(
+        out_ref[...], jnp.max(jnp.abs(x_ref[...]), axis=0, keepdims=True))
 
 
 def _quantize_kernel(x_ref, scale_ref, q_ref):
@@ -77,11 +81,11 @@ def absmax_pallas(g: jax.Array, *, block_rows: int = 256,
         _absmax_kernel,
         grid=(x.shape[0] // block_rows,),
         in_specs=[pl.BlockSpec((block_rows, _LANES), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
+        out_specs=pl.BlockSpec((1, _LANES), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, _LANES), jnp.float32),
         interpret=interpret,
     )(x)
-    return out[0, 0]
+    return jnp.max(out)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))

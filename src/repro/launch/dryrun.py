@@ -23,7 +23,6 @@ import jax
 
 from repro.configs.base import SHAPES, TrainConfig, shape_applicable
 from repro.configs.registry import ARCH_IDS, get_config, get_shape
-from repro.dist.compat import use_mesh
 from repro.launch import hlo_stats
 from repro.launch.mesh import describe, make_production_mesh
 from repro.launch.steps import cell_shardings, input_specs, step_fn_for
@@ -55,7 +54,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     in_sh_tuple = tuple(in_sh[k] for k in specs)
 
     t0 = time.perf_counter()
-    with use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         jitted = jax.jit(fn, in_shardings=in_sh_tuple, out_shardings=out_sh,
                          donate_argnums=donate or None)
         lowered = jitted.lower(*args)

@@ -25,7 +25,6 @@ import jax
 
 from repro.configs.base import TrainConfig
 from repro.configs.registry import ARCH_IDS, get_config, get_shape
-from repro.dist.compat import use_mesh
 from repro.launch import hlo_stats
 from repro.launch.hw import DEFAULT_CHIP
 from repro.launch.mesh import make_production_mesh
@@ -66,7 +65,7 @@ def main() -> int:
     specs = input_specs(cfg, shape)
     in_sh, out_sh = cell_shardings(cfg, shape, mesh, specs)
     fn = step_fn_for(cfg, shape, TrainConfig())
-    with use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         compiled = jax.jit(
             fn, in_shardings=tuple(in_sh[k] for k in specs),
             out_shardings=out_sh,

@@ -17,20 +17,20 @@ module must not touch jax device state (the dry-run sets
 """
 from __future__ import annotations
 
-from jax.sharding import Mesh
-
-from repro.dist.compat import make_mesh
+import jax
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return make_named_mesh(shape, axes)
 
 
 def make_named_mesh(shape: tuple[int, ...], axes: tuple[str, ...]) -> Mesh:
-    """Arbitrary mesh with the standard axis types (tests / small dry-runs)."""
-    return make_mesh(shape, axes)
+    """Arbitrary mesh with Auto axis types (tests / small dry-runs)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_chips(mesh: Mesh) -> int:

@@ -28,6 +28,7 @@ import numpy as np
 
 from repro.configs.registry import ARCH_IDS, get_config, get_smoke_config
 from repro.core import FaultInjector, LegioPolicy
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.mpi import Session
 from repro.serve import RECOVERY_PRESETS, Request, ServeEngine, recovery_preset
@@ -145,6 +146,7 @@ def main(argv: list[str] | None = None) -> int:
                          "(jax when >1 device is visible)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch) if args.full else get_smoke_config(args.arch)
     pairs = []
     for s in args.fail:

@@ -26,6 +26,7 @@ from repro.core import (
     ResilientTrainer,
     VirtualCluster,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def parse_failures(specs: list[str]) -> FaultInjector:
@@ -36,7 +37,9 @@ def parse_failures(specs: list[str]) -> FaultInjector:
     return FaultInjector.at(pairs)
 
 
-def main(argv: list[str] | None = None) -> int:
+def build_trainer(argv: list[str] | None = None
+                  ) -> tuple[ResilientTrainer, argparse.Namespace]:
+    """Parse the CLI and build the trainer it describes; runs no step."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=ARCH_IDS, default="llama3.2-3b")
     ap.add_argument("--full", action="store_true",
@@ -104,7 +107,13 @@ def main(argv: list[str] | None = None) -> int:
     trainer = ResilientTrainer(
         cfg, tc, cluster, per_shard_batch=args.per_shard_batch,
         seq_len=args.seq_len, checkpointer=ckpt)
+    return trainer, args
 
+
+def main(argv: list[str] | None = None) -> int:
+    enable_compile_cache()
+    trainer, args = build_trainer(argv)
+    cluster, cfg = trainer.cluster, trainer.cfg
     print(f"[train] arch={cfg.name} nodes={args.nodes} "
           f"legions(k)={cluster.topo.k} steps={args.steps}")
     for _ in range(args.steps):

@@ -8,7 +8,7 @@ from repro.core.agreement import (
     agree_fault,
     agreement_rounds,
 )
-from repro.dist.compat import make_mesh
+from repro.launch.mesh import make_named_mesh
 
 
 @given(data=st.data())
@@ -49,7 +49,7 @@ def test_agreement_rounds_log():
 
 
 def test_liveness_psum_single_axis():
-    mesh = make_mesh((1,), ("data",))
+    mesh = make_named_mesh((1,), ("data",))
     bitmaps = jnp.array([[1, 0, 1, 1]], jnp.int32)
     out = agree_bitmap_inprogram(mesh, bitmaps)
     np.testing.assert_array_equal(out, [1, 0, 1, 1])
@@ -57,7 +57,7 @@ def test_liveness_psum_single_axis():
 
 def test_bitmap_and_reduce_host():
     """Multiple shards, host fallback path: AND of all rows."""
-    mesh = make_mesh((1,), ("x",))
+    mesh = make_named_mesh((1,), ("x",))
     bitmaps = jnp.array([[1, 1, 0], [1, 0, 1]], jnp.int32)
     out = agree_bitmap_inprogram(mesh, bitmaps)
     np.testing.assert_array_equal(out, [1, 0, 0])

@@ -89,6 +89,10 @@ def test_reduce_unsupported_falls_back_to_sim():
     # unknown op: sim fold
     out2 = jx.reduce([np.ones(3, np.float32)] * 2, np.subtract)
     np.testing.assert_array_equal(out2, np.zeros(3))
+    # each fallback is counted; a call that runs on device is not
+    assert jx.fallbacks == 2
+    jx.reduce([np.ones(3, np.float32)] * 2, np.add)
+    assert jx.fallbacks == 2
 
 
 def test_bcast_and_gather_bit_roundtrip():

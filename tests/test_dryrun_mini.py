@@ -22,11 +22,11 @@ SCRIPT = textwrap.dedent("""
 
     from repro.configs.base import ShapeSpec, TrainConfig
     from repro.configs.registry import get_smoke_config
-    from repro.dist.compat import make_mesh, use_mesh
     from repro.launch import hlo_stats
+    from repro.launch.mesh import make_named_mesh
     from repro.launch.steps import cell_shardings, input_specs, step_fn_for
 
-    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_named_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = get_smoke_config("llama3.2-3b").replace(n_layers=4)
     out = {}
     for shape in (ShapeSpec("mini_train", 64, 8, "train"),
@@ -34,7 +34,7 @@ SCRIPT = textwrap.dedent("""
         specs = input_specs(cfg, shape)
         in_sh, out_sh = cell_shardings(cfg, shape, mesh, specs)
         fn = step_fn_for(cfg, shape, TrainConfig())
-        with use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             jitted = jax.jit(fn, in_shardings=tuple(in_sh[k] for k in specs),
                              out_shardings=out_sh)
             compiled = jitted.lower(*specs.values()).compile()
@@ -53,7 +53,9 @@ SCRIPT = textwrap.dedent("""
 def mini_result():
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
-    env.pop("JAX_PLATFORMS", None)
+    # 8 fake CPU devices only: on a host with libtpu the child must not
+    # try the chip
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                           capture_output=True, text=True, timeout=600)
     assert proc.returncode == 0, proc.stderr[-2000:]

@@ -2,21 +2,21 @@
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs.registry import get_smoke_config
-from repro.dist.compat import abstract_mesh, make_mesh
 from repro.dist.sharding import (
     _batch_dim_axes,
     batch_specs,
     param_specs,
 )
 from repro.launch import hlo_stats
+from repro.launch.mesh import make_named_mesh
 from repro.models import api
 
 
 def mesh_1():
-    return make_mesh((1, 1), ("data", "model"))
+    return make_named_mesh((1, 1), ("data", "model"))
 
 
 def test_param_spec_rules(key):
@@ -46,7 +46,7 @@ def test_ssm_param_specs(key):
 def test_sanitize_spec_drops_nondivisible():
     """jit argument shardings need exact divisibility (constraints pad)."""
     from repro.dist.sharding import sanitize_spec
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = AbstractMesh((16, 16), ("data", "model"))
     # kv-head dim 8 can't shard over model=16 -> dropped; batch 128 can
     s = sanitize_spec(P(None, "data", None, "model", None),
                       (56, 128, 4096, 8, 128), mesh)
@@ -58,7 +58,7 @@ def test_sanitize_spec_drops_nondivisible():
     s3 = sanitize_spec(P("model", None), (32768, 768), mesh)
     assert s3 == P("model")
     # tuple axes: product must divide
-    mp = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    mp = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
     s4 = sanitize_spec(P(("pod", "data"), None), (64, 8), mp)
     assert s4 == P(("pod", "data"))
     s5 = sanitize_spec(P(("pod", "data"), None), (16, 8), mp)
@@ -72,7 +72,7 @@ def test_sanitize_spec_warns_once_per_replicated_dim():
     import warnings
     from repro.dist import sharding
     from repro.dist.sharding import sanitize_spec
-    mesh = abstract_mesh((4, 2), ("data", "model"))
+    mesh = AbstractMesh((4, 2), ("data", "model"))
     sharding._replication_warned.clear()
     with pytest.warns(UserWarning, match=r"dim 0 of blk\.wq.*'data'"):
         s = sanitize_spec(P("data", "model"), (7, 6), mesh, param="blk.wq")
@@ -95,7 +95,7 @@ def test_param_specs_warning_names_the_leaf():
     """The warning carries the dotted tree path of the offending leaf."""
     import warnings
     from repro.dist import sharding
-    mesh = abstract_mesh((4, 2), ("data", "model"))
+    mesh = AbstractMesh((4, 2), ("data", "model"))
     params = {"layers": {"attn": {"wq": jnp.zeros((7, 6))}}}
     sharding._replication_warned.clear()
     with pytest.warns(UserWarning, match=r"layers\.attn\.wq"):
@@ -106,11 +106,11 @@ def test_param_specs_warning_names_the_leaf():
 
 def test_batch_axes_divisibility():
     # AbstractMesh carries shape/axis_names without needing 2 real devices
-    mesh = abstract_mesh((2, 1), ("data", "model"))
+    mesh = AbstractMesh((2, 1), ("data", "model"))
     assert _batch_dim_axes(mesh, 4) == "data"
     assert _batch_dim_axes(mesh, 1) is None            # long_500k: replicated
     assert _batch_dim_axes(mesh, 3) is None
-    mp = abstract_mesh((2, 4, 1), ("pod", "data", "model"))
+    mp = AbstractMesh((2, 4, 1), ("pod", "data", "model"))
     assert _batch_dim_axes(mp, 16) == ("pod", "data")
     assert _batch_dim_axes(mp, 4) == "data"            # pod dropped first
 
