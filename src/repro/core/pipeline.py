@@ -27,9 +27,10 @@ step-boundary seam:
 
 Each drain emits one terminal :class:`RecoveryAction` per disjoint scope —
 the scopes partition the agreed verdict, so every failed node still appears
-in exactly one terminal action. Per-stage wall latencies are recorded on
-every action and in ``traces`` (benchmarks/repair_time.py reads the
-breakdown, and :class:`~repro.core.strategy.CostModelStrategy` fits its
+in exactly one terminal action. Each stage runs in a
+``legio.pipeline.<stage>`` span (:mod:`repro.spans`); its wall seconds are
+recorded on every action and in ``traces`` (benchmarks/repair_time.py reads
+the breakdown, and :class:`~repro.core.strategy.CostModelStrategy` fits its
 per-stage EWMA estimates from the same records — the pipeline is the
 adaptive scorer's only latency oracle).
 
@@ -52,9 +53,9 @@ Invariants (asserted by tests/test_pipeline.py and tests/test_serve.py):
 """
 from __future__ import annotations
 
-import time
 from typing import TYPE_CHECKING, Callable, Iterable
 
+from repro import spans
 from repro.core.agreement import agree_fault
 from repro.core.detector import notice_fault
 from repro.core.types import (
@@ -242,31 +243,36 @@ class FaultPipeline:
         srcs = frozenset(sources)
         timings: dict[str, float] = {}
 
-        t0 = time.perf_counter()
-        events = self._detect(step, srcs)
-        timings["detect"] = time.perf_counter() - t0
+        with spans.span("legio.pipeline.detect", step=step) as sp:
+            events = self._detect(step, srcs)
+        timings["detect"] = sp.seconds
         if not events:
             return []
 
-        t0 = time.perf_counter()
-        observations, suspicion_only = self._notice(events)
-        timings["notice"] = time.perf_counter() - t0
+        with spans.span("legio.pipeline.notice", step=step,
+                        events=len(events)) as sp:
+            observations, suspicion_only = self._notice(events)
+        timings["notice"] = sp.seconds
 
-        t0 = time.perf_counter()
-        verdict = self._agree(observations, suspicion_only)
-        timings["agree"] = time.perf_counter() - t0
+        with spans.span("legio.pipeline.agree", step=step) as sp:
+            verdict = self._agree(observations, suspicion_only)
+            sp.set(verdict=len(verdict))
+        timings["agree"] = sp.seconds
         if not verdict:
             return []
         if gate is not None:
             gate(verdict)
 
-        t0 = time.perf_counter()
-        strategy_name, straggle, scopes = self._plan(verdict, events)
-        timings["plan"] = time.perf_counter() - t0
+        with spans.span("legio.pipeline.plan", step=step,
+                        verdict=len(verdict)) as sp:
+            strategy_name, straggle, scopes = self._plan(verdict, events)
+        timings["plan"] = sp.seconds
 
-        t0 = time.perf_counter()
-        repaired = self._apply(verdict, straggle, scopes)
-        timings["apply"] = time.perf_counter() - t0
+        # the data plane's reshard (legio.reshard) runs inside apply
+        with spans.span("legio.pipeline.apply", step=step,
+                        verdict=len(verdict)) as sp:
+            repaired = self._apply(verdict, straggle, scopes)
+        timings["apply"] = sp.seconds
 
         sources = tuple(sorted({e.source for e in events},
                                key=lambda s: s.value))

@@ -8,7 +8,8 @@ This is the production integration of the paper's technique: a jitted
   * a failure (injected here; heartbeat-detected in production) triggers the
     Legio repair path — agreement, hierarchical shrink, master re-election —
     and the trainer then (a) rebuilds its mesh from survivors, (b) reshards
-    params/optimizer state, (c) recompiles through the CompileCache;
+    params/optimizer state, (c) recompiles the step for the new batch shape
+    (jit's own cache, and JAX's persistent cache where it is enabled);
   * the global batch shrinks (DROP) or redistributes (REBALANCE); gradient
     means renormalize over the shards actually computed, so the SGD
     estimator stays unbiased — the paper's Monte-Carlo argument, applied to
@@ -22,7 +23,6 @@ code path shrinks physical meshes — the dry-run proves those lower/compile.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from functools import partial
 from typing import Any
@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.core.cr import LegionCheckpointer
 from repro.core.executor import VirtualCluster
@@ -147,13 +148,15 @@ class ResilientTrainer:
             s for a in cl.plan.assignments for s in a.shards)
         if not shards:
             raise RuntimeError("no surviving shards — cluster exhausted")
-        parts = [
-            make_batch(self.tc.seed, step, s, batch=self.per_shard_batch,
-                       seq_len=self.seq_len, vocab_size=self.cfg.vocab_size)
-            for s in shards
-        ]
-        batch = {k: jnp.concatenate([p[k] for p in parts], axis=0)
-                 for k in parts[0]}
+        with spans.span("legio.train.batch", shards=len(shards)):
+            parts = [
+                make_batch(self.tc.seed, step, s, batch=self.per_shard_batch,
+                           seq_len=self.seq_len,
+                           vocab_size=self.cfg.vocab_size)
+                for s in shards
+            ]
+            batch = {k: jnp.concatenate([p[k] for p in parts], axis=0)
+                     for k in parts[0]}
         # mean-over-present-shards is already the renormalized estimator;
         # grad_scale stays 1.0 for DROP (the mean denominator shrank with
         # the batch). It differs from 1 only for weighted schemes.
@@ -163,46 +166,56 @@ class ResilientTrainer:
 
     def run_step(self) -> TrainerReport:
         cl = self.cluster
-        t0 = time.perf_counter()
         step = self.step
+        with spans.span("legio.train.step", step_trace=True, step=step,
+                        shards=cl.plan.active_shards) as sp:
+            # step boundary through the facade: the provisioner delivers
+            # re-spawned spares and warmed-up non-blocking substitutes
+            # rejoin before new shards are handed out (re-expansion = mesh
+            # change too); ground-truth faults land and drain through the
+            # pipeline's INJECTED channel — detect → notice → agree → plan
+            # → apply — so the trainer repairs through the registered
+            # RecoveryStrategy, not a side door. (charge=False: the
+            # trainer's clock is wall time.)
+            with spans.span("legio.train.boundary", step=step):
+                boundary = self.session.boundary(
+                    step, observe_injected=True, charge=False)
+            repair = None
+            recompiled = bool(boundary.expansions)
+            if boundary.actions:
+                repair = boundary.actions[0].report
+                recompiled = True  # mesh change forces re-lower unless cached
+            sp.set(shards=cl.plan.active_shards)
 
-        # step boundary through the facade: the provisioner delivers
-        # re-spawned spares and warmed-up non-blocking substitutes rejoin
-        # before new shards are handed out (re-expansion = mesh change
-        # too); ground-truth faults land and drain through the pipeline's
-        # INJECTED channel — detect → notice → agree → plan → apply — so
-        # the trainer repairs through the registered RecoveryStrategy, not
-        # a side door. (charge=False: the trainer's clock is wall time.)
-        boundary = self.session.boundary(step, observe_injected=True,
-                                         charge=False)
-        repair = None
-        recompiled = bool(boundary.expansions)
-        if boundary.actions:
-            repair = boundary.actions[0].report
-            recompiled = True  # mesh change forces re-lower unless cached
+            batch, grad_scale = self._global_batch(step)
+            # a fault step's recompile (or persistent-cache fetch) lands here
+            with spans.span("legio.train.dispatch", step=step):
+                params, opt, metrics = self.train_step(
+                    self.params, self.opt, batch,
+                    jnp.asarray(grad_scale, jnp.float32))
+            self.params, self.opt = params, opt
 
-        batch, grad_scale = self._global_batch(step)
-        params, opt, metrics = self.train_step(
-            self.params, self.opt, batch, jnp.asarray(grad_scale, jnp.float32))
-        self.params, self.opt = params, opt
+            with spans.span("legio.train.sync", step=step):
+                loss = float(metrics["loss"])
+            if not np.isfinite(loss):
+                raise FloatingPointError(
+                    f"non-finite loss at step {step}: {loss}")
 
-        loss = float(metrics["loss"])
-        if not np.isfinite(loss):
-            raise FloatingPointError(f"non-finite loss at step {step}: {loss}")
-
-        if self.checkpointer is not None and self.tc.checkpoint_every > 0 \
-                and step > 0 and step % self.tc.checkpoint_every == 0:
-            self.checkpointer.save(step, cl.topo, self._state_of, sync=False)
+            if self.checkpointer is not None and self.tc.checkpoint_every > 0 \
+                    and step > 0 and step % self.tc.checkpoint_every == 0:
+                self.checkpointer.save(step, cl.topo, self._state_of,
+                                       sync=False)
+            grad_norm = float(metrics.get("grad_norm", 0.0))
 
         report = TrainerReport(
             step=step,
             loss=loss,
-            grad_norm=float(metrics.get("grad_norm", 0.0)),
+            grad_norm=grad_norm,
             active_shards=cl.plan.active_shards,
             grad_scale=grad_scale,
             repair=repair,
             recompiled=recompiled,
-            step_seconds=time.perf_counter() - t0,
+            step_seconds=sp.seconds,
             metrics={k: float(v) for k, v in metrics.items()
                      if np.ndim(v) == 0},
         )

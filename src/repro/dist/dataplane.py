@@ -30,7 +30,6 @@ This module never imports ``repro.core`` (the cluster imports it lazily).
 from __future__ import annotations
 
 import functools
-import time
 from dataclasses import dataclass
 from typing import Any, Callable
 
@@ -39,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import spans
 from repro.dist.sharding import param_specs
 from repro.kernels.ops import interpret_mode
 from repro.kernels.quantize import absmax_pallas, quantize_int8_with_scale
@@ -65,7 +65,7 @@ class ReshardReport:
     leaves: int
     n_devices: int                   # devices in the survivors' mesh
     moved_bytes: int
-    wall_seconds: float              # measured device_put + block_until_ready
+    wall_seconds: float              # legio.reshard: device_put + block_until_ready
     mesh_shape: tuple[int, int]      # ("data", "model")
 
 
@@ -283,12 +283,12 @@ class JaxDataPlane(DataPlane):
         if not entries:
             return None
         mesh = self.mesh_for(view)
-        t0 = time.perf_counter()
-        leaves, nbytes = self._place(entries, mesh)
-        wall = time.perf_counter() - t0
+        with spans.span("legio.reshard", devices=mesh.size) as sp:
+            leaves, nbytes = self._place(entries, mesh)
+            sp.set(leaves=leaves, bytes=nbytes)
         return ReshardReport(names=tuple(entries), leaves=leaves,
                              n_devices=mesh.size, moved_bytes=nbytes,
-                             wall_seconds=wall,
+                             wall_seconds=sp.seconds,
                              mesh_shape=tuple(mesh.devices.shape))
 
 

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import spans
 from repro.configs.registry import ARCH_IDS, get_config, get_smoke_config
 from repro.core import FaultInjector, LegioPolicy
 from repro.launch.compile_cache import enable_compile_cache
@@ -48,9 +49,16 @@ class ResilientServer:
         self.decode_tokens = decode_tokens
         key = jax.random.PRNGKey(0)
         self.params = api.init_params(cfg, key)
-        self._prefill = jax.jit(
-            lambda p, t: api.prefill(cfg, p, t, prompt_len + decode_tokens))
-        self._decode = jax.jit(lambda p, c, t: api.decode_step(cfg, p, c, t))
+
+        # named, so that a profile's program executions tell them apart
+        def prefill(p, t):
+            return api.prefill(cfg, p, t, prompt_len + decode_tokens)
+
+        def decode(p, c, t):
+            return api.decode_step(cfg, p, c, t)
+
+        self._prefill = jax.jit(prefill)
+        self._decode = jax.jit(decode)
         # tail batches change shape and recompile the jitted prefill/decode;
         # that wall-clock noise must not soft-fail healthy nodes as stragglers
         self.engine = ServeEngine(cluster, self._work_fn,
@@ -71,20 +79,27 @@ class ResilientServer:
     def _work_batch(self, request_ids: list[int]) -> np.ndarray:
         """Prefill + greedy-decode a batch of requests; returns token matrix."""
         B = len(request_ids)
-        key = jax.random.PRNGKey(1234)
-        tokens = jax.random.randint(
-            key, (B, self.prompt_len), 0, self.cfg.vocab_size, jnp.int32)
-        # deterministic per-request prompts (request id folds into row 0)
-        tokens = tokens.at[:, 0].set(
-            jnp.asarray(request_ids, jnp.int32) % self.cfg.vocab_size)
-        logits, cache = self._prefill(self.params, tokens)
-        out = []
-        tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(jnp.int32)
-        for _ in range(self.decode_tokens):
-            out.append(tok)
-            logits, cache = self._decode(self.params, cache, tok)
-            tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(jnp.int32)
-        return np.asarray(jnp.concatenate(out, axis=1))
+        with spans.span("legio.serve.prefill", batch=B):
+            key = jax.random.PRNGKey(1234)
+            tokens = jax.random.randint(
+                key, (B, self.prompt_len), 0, self.cfg.vocab_size, jnp.int32)
+            # deterministic per-request prompts (request id folds into row 0)
+            tokens = tokens.at[:, 0].set(
+                jnp.asarray(request_ids, jnp.int32) % self.cfg.vocab_size)
+            logits, cache = self._prefill(self.params, tokens)
+        with spans.span("legio.serve.decode", batch=B,
+                        steps=self.decode_tokens):
+            out = []
+            tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(
+                jnp.int32)
+            for _ in range(self.decode_tokens):
+                out.append(tok)
+                logits, cache = self._decode(self.params, cache, tok)
+                tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(
+                    jnp.int32)
+            tokens = jnp.concatenate(out, axis=1)
+        with spans.span("legio.serve.fetch", batch=B):
+            return np.asarray(tokens)
 
     def run(self, n_requests: int) -> dict:
         self.engine.submit(n_requests)

@@ -61,10 +61,10 @@ Invariants (asserted by tests/test_serve.py and the chaos harness):
 from __future__ import annotations
 
 import math
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable
 
+from repro import spans
 from repro.core.executor import VirtualCluster
 from repro.core.types import FaultSource, RecoveryAction
 from repro.mpi import Session
@@ -127,7 +127,7 @@ class RoundReport:
     backlog: int = 0
     inflight: int = 0
     sim_seconds: float = 0.0                 # deterministic round duration
-    wall_seconds: float = 0.0                # perf_counter, humans only
+    wall_seconds: float = 0.0                # the legio.serve.round span
 
 
 @dataclass
@@ -348,52 +348,58 @@ class ServeEngine:
     def run_round(self, step: int | None = None) -> RoundReport:
         cl = self.cluster
         step = self.round_count if step is None else step
-        t_start = time.perf_counter()
-        sim_start = cl.clock.sim_seconds
+        with spans.span("legio.serve.round", step_trace=True,
+                        step=step) as sp:
+            sim_start = cl.clock.sim_seconds
 
-        # 1. boundary: elastic refills + warmed-up substitutes rejoin
-        boundary = self.session.deliver(step)
+            # 1. boundary: elastic refills + warmed-up substitutes rejoin
+            with spans.span("legio.serve.deliver", step=step):
+                boundary = self.session.deliver(step)
 
-        # 2. admit against a pinned snapshot — a repair can neither run
-        #    nor tear the structure while windows are being filled
-        dispatched_sizes = self._admit_phase(step)
+            # 2. admit against a pinned snapshot — a repair can neither run
+            #    nor tear the structure while windows are being filled
+            with spans.span("legio.serve.admit", step=step):
+                dispatched_sizes = self._admit_phase(step)
+            sp.set(dispatched=sum(dispatched_sizes.values()))
 
-        # 3. faults land mid-flight; the sim clock ticks
-        self.session.inject(step)
+            # 3. faults land mid-flight; the sim clock ticks
+            with spans.span("legio.serve.inject", step=step):
+                self.session.inject(step)
 
-        # 4. execute — live busy nodes advance/complete, dead ones keep
-        #    their windows until the drain migrates them
-        completed_before = len(self.completed)
-        if self.continuous:
-            self._tick_phase(step)
-        else:
-            self._lockstep_phase(step)
-        for node in cl.live_nodes:
-            cl.detector.beat(node, cl.clock.sim_seconds)
+            # 4. execute — live busy nodes advance/complete, dead ones keep
+            #    their windows until the drain migrates them
+            completed_before = len(self.completed)
+            with spans.span("legio.serve.execute", step=step):
+                if self.continuous:
+                    self._tick_phase(step)
+                else:
+                    self._lockstep_phase(step)
+                for node in cl.live_nodes:
+                    cl.detector.beat(node, cl.clock.sim_seconds)
 
-        # 5. the result gather, as one interposed facade call: the lost
-        #    nodes' PROC_FAILED is trapped among the busy set, the crash
-        #    channels drain, and the pipeline listener migrates verdict
-        #    nodes' windows before the call returns
-        requeues_before = self.metrics.requeues
-        self._comm.gather(among=set(self._slots))
-        self.session.poll((FaultSource.STRAGGLER,))
-        actions = list(self.session.take_actions())
-        # safety net: a dead node whose loss produced no verdict this round
-        # (e.g. no surviving observer) still must not strand its window —
-        # redeliver now; the heartbeat channel will confirm the node later
-        stranded_view = None
-        for node in [n for n in list(self._slots) if n in cl.failed]:
-            batch = self._pop_node(node)
-            if batch and stranded_view is None:
-                stranded_view = cl.topo.view()
-            for req in batch:
-                self._redeliver(req, stranded_view, migrate=True)
+            # 5. the result gather, as one interposed facade call: the lost
+            #    nodes' PROC_FAILED is trapped among the busy set, the crash
+            #    channels drain, and the pipeline listener migrates verdict
+            #    nodes' windows before the call returns
+            requeues_before = self.metrics.requeues
+            with spans.span("legio.serve.gather", step=step):
+                self._comm.gather(among=set(self._slots))
+                self.session.poll((FaultSource.STRAGGLER,))
+                actions = list(self.session.take_actions())
+                # safety net: a dead node whose loss produced no verdict
+                # this round (e.g. no surviving observer) still must not
+                # strand its window — redeliver now; the heartbeat channel
+                # will confirm the node later
+                stranded_view = None
+                for node in [n for n in list(self._slots) if n in cl.failed]:
+                    batch = self._pop_node(node)
+                    if batch and stranded_view is None:
+                        stranded_view = cl.topo.view()
+                    for req in batch:
+                        self._redeliver(req, stranded_view, migrate=True)
 
-        self.round_count = step + 1
-        sim_elapsed = cl.clock.sim_seconds - sim_start
-        wall = time.perf_counter() - t_start
-        self.metrics.record_round(step, sim_elapsed, wall)
+            self.round_count = step + 1
+            sim_elapsed = cl.clock.sim_seconds - sim_start
         return RoundReport(
             step=step,
             dispatched=dispatched_sizes,
@@ -405,7 +411,7 @@ class ServeEngine:
             backlog=self.router.backlog,
             inflight=sum(len(b) for b in self._inflight.values()),
             sim_seconds=sim_elapsed,
-            wall_seconds=wall,
+            wall_seconds=sp.seconds,
         )
 
     # -- phases --------------------------------------------------------------
@@ -476,10 +482,11 @@ class ServeEngine:
         its progress reset (the result never materialized), never records
         a completion the client didn't get."""
         cl = self.cluster
-        t0 = time.perf_counter()
-        results = self.work_fn(node, ready, step)
+        with spans.span("legio.serve.work", node=node, batch=len(ready),
+                        rids=tuple(r.rid for r in ready)) as sp:
+            results = self.work_fn(node, ready, step)
         if self.observe_stragglers:
-            cl.straggler.observe(node, time.perf_counter() - t0)
+            cl.straggler.observe(node, sp.seconds)
         dropped_view = None
         for req in ready:
             if req.rid in results:

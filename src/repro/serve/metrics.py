@@ -5,9 +5,9 @@ Latency is recorded twice per completion: in *rounds* (the legacy unit)
 and in *simulated-clock seconds* (``arrival_sim`` → ``complete_sim``, the
 cluster's deterministic clock). The sim-seconds numbers are what the
 load-curve benchmark asserts on — they are byte-identical across runs
-given a seeded campaign, per the repo's structural-benchmark convention —
-while wall time (``time.perf_counter``) is kept alongside per round for
-human inspection only, never for pass/fail.
+given a seeded campaign, per the repo's structural-benchmark convention.
+A round's wall time is ``RoundReport.wall_seconds``, its
+``legio.serve.round`` span (repro.spans).
 
 The continuous-batching engine also feeds:
 
@@ -73,8 +73,6 @@ class ServeMetrics:
     dispatch_trace: dict[int, dict[int, int]] = field(default_factory=dict)
     # backlog + free capacity but nothing admitted: step -> [legions]
     starvation_trace: dict[int, list[int]] = field(default_factory=dict)
-    # per-round duration, sim seconds and wall seconds side by side
-    round_seconds: dict[int, dict[str, float]] = field(default_factory=dict)
 
     # -- recording -----------------------------------------------------------
 
@@ -84,9 +82,6 @@ class ServeMetrics:
 
     def record_starved(self, step: int, legion: int) -> None:
         self.starvation_trace.setdefault(step, []).append(legion)
-
-    def record_round(self, step: int, sim: float, wall: float) -> None:
-        self.round_seconds[step] = {"sim": sim, "wall": wall}
 
     def record_completion(self, rec: CompletionRecord) -> None:
         self.completions.append(rec)

@@ -308,19 +308,6 @@ def test_default_specs_match_legacy_single_round_completion():
     assert eng.metrics.phase_ticks == {"prefill": 9, "decode": 0}
 
 
-def test_round_seconds_records_sim_and_wall():
-    """Every round records its duration on both clocks: the simulated one
-    (deterministic — one tick per continuous round) and perf_counter."""
-    eng = make_engine(n=8)
-    eng.submit(12)
-    eng.serve(max_rounds=10)
-    tick = eng.cluster.policy.step_sim_seconds
-    assert eng.metrics.round_seconds, "rounds must be recorded"
-    for row in eng.metrics.round_seconds.values():
-        assert row["sim"] == pytest.approx(tick)
-        assert row["wall"] >= 0.0
-
-
 # ---------------------------------------------------------------------------
 # decode-state migration: progress survives the node, never double-completes
 # ---------------------------------------------------------------------------
